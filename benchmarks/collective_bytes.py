@@ -13,8 +13,8 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.parallel.ring_attention import (
     layer_dataflow_attention,
@@ -28,7 +28,7 @@ N_SHARDS = 8
 
 def _lower(fn, mesh, shapes, dtype):
     specs = (P(None, "sp"),) * 3
-    sm = shard_map(fn, mesh=mesh, in_specs=specs, out_specs=P(None, "sp"))
+    sm = jax.shard_map(fn, mesh=mesh, in_specs=specs, out_specs=P(None, "sp"))
     args = [jax.ShapeDtypeStruct(s, dtype) for s in shapes]
     return jax.jit(sm).lower(*args).compile()
 
@@ -46,7 +46,7 @@ def run() -> list[dict]:
         print("needs 8 devices — set "
               "XLA_FLAGS=--xla_force_host_platform_device_count=8")
         return []
-    mesh = jax.make_mesh((8,), ("sp",))
+    mesh = jax.make_mesh((8,), ("sp",), axis_types=(AxisType.Auto,))
     rows = []
     b, s, h, d = 2, 8192, 16, 128
     shapes = [(b, s, h, d)] * 3

@@ -14,10 +14,12 @@ via the BlockSpec index map), and an LSE output per query — the LSE is
 what makes the token-dataflow distributed merges (ring attention,
 split-KV decode) exact, because Eq. 5 is associative across shards.
 
-Grid: (batch, q_heads, Sq/bq, Sk/bk), K innermost; the output and the
-(m, l) running statistics are revisited blocks accumulated across the K
-axis.  m/l are carried in f32 output refs of shape (..., bq) — lane-dim
-aligned.  Finalization (o /= l, lse = m + log l) happens at the last K step.
+Grid: (batch, q_heads, Sq/bq, Sk/bk), K innermost; the output is a
+revisited block accumulated across the K axis, and the (m, l) running
+statistics live in (bq, 1) f32 VMEM scratch.  Per-row outputs (lse,
+the visit count) are (B, Hq, Sq, 1) arrays so every block's last two
+dims sit on the TPU's (8, 128) tiling.  Finalization (o /= l,
+lse = m + log l) happens at the last K step.
 
 Block skipping: under a causal mask, K blocks strictly above the
 diagonal are skipped; with a sliding window, K blocks that fall entirely
@@ -33,6 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -40,13 +43,13 @@ NEG_INF = -1e30
 def _interpret_default() -> bool:
     """Single source of truth for Pallas interpret-mode resolution:
     compiled Mosaic on TPU, the interpreter everywhere else.  Shared by
-    `flash_attention_kernel`, `ops.flash_attention`, and the paged
-    kernel (`kernels.paged_attention`)."""
+    `flash_attention_kernel`, `ops.flash_attention`, the paged kernel
+    (`kernels.paged_attention`) and `sc_matmul_quantized`."""
     return jax.default_backend() != "tpu"
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                  nvis_ref, *, scale: float, causal: bool,
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, nvis_ref,
+                  m_ref, l_ref, *, scale: float, causal: bool,
                   window: int | None, kv_len: int | None,
                   nk: int, bq: int, bk: int):
     qi = pl.program_id(2)
@@ -79,15 +82,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                 keep &= cols < kv_len
             s = jnp.where(keep, s, NEG_INF)
 
-        m_prev = m_ref[0, 0]                          # (bq,)
-        l_prev = l_ref[0, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_prev = m_ref[...]                           # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[0, 0] = l_prev * alpha + jnp.sum(p, axis=-1)
-        m_ref[0, 0] = m_new
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[...] = m_new
         nvis_ref[0, 0] = nvis_ref[0, 0] + 1.0
-        o_ref[0, 0] = (o_ref[0, 0] * alpha[:, None]
+        o_ref[0, 0] = (o_ref[0, 0] * alpha
                        + jax.lax.dot_general(
                            p, v, (((1,), (0,)), ((), ())),
                            preferred_element_type=jnp.float32))
@@ -114,9 +116,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[0, 0], 1e-30)
-        o_ref[0, 0] = o_ref[0, 0] / l[:, None]
-        lse_ref[0, 0] = m_ref[0, 0] + jnp.log(l)
+        l = jnp.maximum(l_ref[...], 1e-30)            # (bq, 1)
+        o_ref[0, 0] = o_ref[0, 0] / l
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 @functools.partial(
@@ -159,7 +161,8 @@ def _flash_attention_all(
         _flash_kernel, scale=scale, causal=causal, window=window,
         kv_len=kv_len, nk=nk, bq=bq, bk=bk,
     )
-    o, lse, _, _, nvis = pl.pallas_call(
+    row = pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+    o, lse, nvis = pl.pallas_call(
         kernel,
         grid=(b, hq, nq, nk),
         in_specs=[
@@ -171,21 +174,19 @@ def _flash_attention_all(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, ki: (bi, hi, qi)),
-            pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, ki: (bi, hi, qi)),
-            pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, ki: (bi, hi, qi)),
-            pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, ki: (bi, hi, qi)),
+            row,   # lse
+            row,   # visited K blocks
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, sq, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),  # m (scratch-ish)
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),  # l (scratch-ish)
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),  # visited K blocks
+            jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),   # m
+                        pltpu.VMEM((bq, 1), jnp.float32)],  # l
         interpret=interpret,
     )(q, k, v)
-    return o, lse, nvis
+    return o, lse[..., 0], nvis[..., 0]
 
 
 def flash_attention_kernel(

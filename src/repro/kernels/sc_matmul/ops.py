@@ -1,8 +1,9 @@
 """jit'd public wrapper around the sc_matmul Pallas kernel.
 
-Owns quantization (per ArithmeticPolicy), block padding, dequantization and
-the CPU-interpret/TPU-compiled switch.  `sc_linear` is the drop-in matmul
-used by repro.models when a policy routes a layer through the kernel path.
+Owns quantization (per ArithmeticPolicy), block padding and
+dequantization; interpret mode resolves inside `sc_matmul_quantized`.
+`sc_linear` is the drop-in matmul used by repro.models when a policy
+routes a layer through the kernel path.
 """
 from __future__ import annotations
 
@@ -24,10 +25,6 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def sc_matmul(
     a: jax.Array,
     b: jax.Array,
@@ -44,8 +41,6 @@ def sc_matmul(
     (modulo sigma_analog, which is emulation-only) — pinned by
     tests/test_kernels.py.
     """
-    if interpret is None:
-        interpret = _interpret_default()
     if bk is None:
         bk = 160 if policy.mode == "artemis" else 256
     a = a.astype(jnp.float32)

@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.flash_attention.flash_attention import _interpret_default
+
 ACC_DEPTH = 20  # MOMCAP consecutive accumulations (paper §III.A.2)
 
 
@@ -115,14 +117,18 @@ def sc_matmul_quantized(
     bm: int = 128,
     bn: int = 128,
     bk: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Blocked ARTEMIS matmul over pre-quantized int8 operands.
 
     aq: (M, K) int8, bq: (K, N) int8; M, N, K must be multiples of the block
     shapes (ops.py pads).  Returns (M, N): int32 for mode="int8" (integer
     dot units), float32 in SC product units otherwise.
+    `interpret=None` resolves via the shared `_interpret_default()`
+    platform probe: compiled on TPU, interpreted elsewhere.
     """
+    if interpret is None:
+        interpret = _interpret_default()
     if bk is None:
         bk = 160 if mode == "artemis" else 256
     m, k = aq.shape

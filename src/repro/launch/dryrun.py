@@ -60,13 +60,8 @@ def _save_cache(cache: dict) -> None:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Version-compatible `compiled.cost_analysis()`: jax <= 0.4.x returns
-    a list with one dict per partitioned program, newer jax returns the
-    dict directly."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """`compiled.cost_analysis()` as a plain dict."""
+    return dict(compiled.cost_analysis())
 
 
 def lower_cell(cfg: ModelConfig, cell: configs.ShapeCell, mesh,

@@ -6,6 +6,7 @@ touches jax device state (device counts lock on first backend init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,7 +17,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     # why: the launch layer's production mesh factory — the serve seam
     # (repro/serve/mesh.py) covers serving; this covers training runs
     # repro: allow[mesh-discipline]
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_smoke_mesh(data: int = 2, model: int = 2):
@@ -24,7 +26,8 @@ def make_smoke_mesh(data: int = 2, model: int = 2):
     # why: test-only mesh factory, same ownership story as the
     # production factory above
     # repro: allow[mesh-discipline]
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_chips(mesh) -> int:

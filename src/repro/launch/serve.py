@@ -43,7 +43,9 @@ import jax.numpy as jnp
 from repro import configs
 from repro.core.policy import ArithmeticPolicy
 from repro.launch import steps as stepslib
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import frontend, model
+from repro.serve.mesh import describe_devices
 
 
 def serve(arch: str = "qwen3_8b", smoke: bool = True,
@@ -235,6 +237,9 @@ def main() -> None:
                          "(exact policy, mesh_shards=1; interpreted "
                          "off-TPU)")
     args = ap.parse_args()
+    print(f"compile cache: {use_compile_cache()}")
+    dev = describe_devices()
+    print(f"device: {dev['platform']} {dev['kind']} x{dev['count']}")
     sampled_fraction = args.sampled_fraction
     if sampled_fraction is None:
         sampled_fraction = 1.0 if args.temperature > 0 else 0.0

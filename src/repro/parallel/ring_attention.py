@@ -129,13 +129,9 @@ def ring_attention(q, k, v, *, axis_name: str, causal: bool = True,
     m0 = jnp.full((b, s_local, h), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, s_local, h), jnp.float32)
     # mark the constant inits as device-varying over the ring axis (the body
-    # outputs are varying; scan carries must type-match under shard_map vma).
-    # jax.lax.pvary only exists once shard_map enforces varying-manual-axes
-    # typing (jax >= 0.5); on older releases the carries already type-match
-    # and the annotation is a no-op.
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        o0, m0, l0 = (pvary(a, axis_name) for a in (o0, m0, l0))
+    # outputs are varying; scan carries must type-match under shard_map vma)
+    o0, m0, l0 = (jax.lax.pcast(a, axis_name, to="varying")
+                  for a in (o0, m0, l0))
     (o, m, l, _, _, _), _ = jax.lax.scan(
         body, (o0, m0, l0, k, v, kv_positions), None, length=n)
     l = jnp.maximum(l, 1e-30)

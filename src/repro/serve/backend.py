@@ -466,8 +466,11 @@ class PagedKVBackend(SequenceBackend):
     # -- mesh seam ----------------------------------------------------------
 
     def _place_params(self, params):
-        """Pin parameters to the mesh's TP shardings; identity (no
-        device_put at all) on the single-device mesh."""
+        """Pin caller-supplied parameters to the mesh's TP shardings;
+        identity (no device_put at all) on the single-device mesh.
+        Parameters the engine initialized (`serve.mesh.init_params`)
+        are created with these shardings already, so this is a no-op
+        for them."""
         shardings = param_shardings(self.mesh, self.cfg, params)
         if shardings is None:
             return params

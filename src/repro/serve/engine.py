@@ -83,12 +83,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.policy import ArithmeticPolicy
-from repro.models import model
 from repro.models.config import ModelConfig
 from repro.serve import sampler
 from repro.serve.backend import EngineConfig, make_backend
 from repro.serve.cost import ArtemisCostModel
-from repro.serve.mesh import make_serve_mesh
+from repro.serve.mesh import init_params, make_serve_mesh
 from repro.serve.obs import (
     PHASES,
     AdmitEvent,
@@ -117,18 +116,18 @@ class ServeEngine:
         self.cfg = cfg
         self.ecfg = ecfg
         self.policy = policy
+        self.mesh = make_serve_mesh(ecfg.mesh_shards)
         if params is None:
             # One-time parameter init, not a sampling key: per-request
             # sampling keys are derived exclusively in sampler.lane_key
             # (fold_in(PRNGKey(request.seed), tokens_generated)).
             # repro: allow[rng-key-discipline]
-            params = model.init(jax.random.PRNGKey(seed), cfg)
+            params = init_params(self.mesh, cfg, jax.random.PRNGKey(seed))
         self.params = params
         self.cost = ArtemisCostModel(cfg, scheme=ecfg.scheme,
                                      n_shards=ecfg.mesh_shards)
         self.obs = Tracer(level=ecfg.observability)
         self.now = 0.0
-        self.mesh = make_serve_mesh(ecfg.mesh_shards)
         self.backend = make_backend(
             cfg, ecfg, policy, params,
             obs=self.obs, clock=lambda: self.now, mesh=self.mesh)

@@ -32,6 +32,8 @@ from typing import Any
 __all__ = [
     "ServeMesh",
     "make_serve_mesh",
+    "describe_devices",
+    "init_params",
     "param_shardings",
     "kv_pool_sharding",
     "replicated",
@@ -73,8 +75,10 @@ def make_serve_mesh(n_shards: int = 1, axis: str = "model") -> ServeMesh:
     if n_shards < 1:
         raise ValueError(f"n_shards must be >= 1, got {n_shards}")
     import jax
+    from jax.sharding import AxisType
     try:
-        handle = jax.make_mesh((n_shards,), (axis,))
+        handle = jax.make_mesh((n_shards,), (axis,),
+                               axis_types=(AxisType.Auto,))
     except ValueError as e:
         raise ValueError(
             f"cannot build a {n_shards}-way serve mesh: {e}. On CPU, "
@@ -82,6 +86,15 @@ def make_serve_mesh(n_shards: int = 1, axis: str = "model") -> ServeMesh:
             f"--xla_force_host_platform_device_count={n_shards} "
             f"(set BEFORE jax initializes)") from e
     return ServeMesh(n_shards=n_shards, axis=axis, handle=handle)
+
+
+def describe_devices() -> dict:
+    """The device inventory as JAX reports it: platform and
+    `device_kind` of device 0, and the device count."""
+    import jax
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
 
 
 # ---------------------------------------------------------------------------
@@ -100,6 +113,22 @@ def param_shardings(mesh: ServeMesh, cfg, params):
     rules = sh.ShardingRules(fsdp=False)
     specs = sh.param_specs(cfg, params, mesh.handle, rules)
     return sh.named(mesh.handle, specs)
+
+
+def init_params(mesh: ServeMesh, cfg, key):
+    """Random parameters from `key`, created where they live: one jitted
+    `model.init` whose outputs carry `param_shardings` on a multi-shard
+    mesh, so no device ever holds the whole model (a 36-layer 8B model
+    fits four chips but not one). On the single mesh the jitted init
+    places everything on the default device — jitted so the random
+    draws fuse instead of holding per-leaf temporaries."""
+    import functools
+
+    import jax
+    from repro.models import model
+    init = functools.partial(model.init, cfg=cfg)
+    shardings = param_shardings(mesh, cfg, jax.eval_shape(init, key))
+    return jax.jit(init, out_shardings=shardings)(key)
 
 
 def kv_pool_sharding(mesh: ServeMesh, cfg):

@@ -334,16 +334,17 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
                      dtype=jnp.float32, sharding=None) -> PagedKVCache:
     """Build the device page pool. `sharding` (a NamedSharding from
     `serve.mesh.kv_pool_sharding`, or None) places the pool across a
-    device mesh; the allocator / block tables stay host-side either
-    way, so page ids are LOGICAL and mesh-oblivious."""
+    device mesh — each device allocates only its own shard, the whole
+    pool never exists on one device; the allocator / block tables stay
+    host-side either way, so page ids are LOGICAL and mesh-oblivious."""
     if cfg.family not in ("dense", "moe"):
         raise ValueError(
             f"paged KV cache needs an attention family, got {cfg.family!r}")
     kv_heads, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     shape = (cfg.n_layers, n_pages, page_size, kv_heads, hd)
-    kv = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-    if sharding is not None:
-        kv = jax.device_put(kv, sharding)
+    zeros = jax.jit(functools.partial(jnp.zeros, shape, dtype),
+                    out_shardings=sharding)
+    kv = {"k": zeros(), "v": zeros()}
     return PagedKVCache(kv=kv, allocator=PageAllocator(n_pages, page_size))
 
 

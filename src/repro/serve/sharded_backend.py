@@ -45,7 +45,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 
 from repro.core.policy import ArithmeticPolicy
 from repro.models.config import ModelConfig
@@ -102,7 +101,7 @@ def _dataflow_attn_core(smesh: ServeMesh):
                 return ring_attention(qc, kc, vc, axis_name=ax,
                                       causal=True, q_positions=qp,
                                       kv_positions=kp)
-            ctx = shard_map(
+            ctx = jax.shard_map(
                 ring, mesh=mesh,
                 in_specs=(seq, seq, seq, seq, seq),
                 out_specs=seq)(q, kall, vall, positions, kv_pos)
@@ -113,7 +112,7 @@ def _dataflow_attn_core(smesh: ServeMesh):
                 return split_kv_attention(qc, kc, vc, axis_name=ax,
                                           q_positions=qp,
                                           kv_positions_local=kp)
-            ctx = shard_map(
+            ctx = jax.shard_map(
                 split, mesh=mesh,
                 in_specs=(rep, seq, seq, rep, seq),
                 out_specs=rep)(q, kall, vall, positions, kv_pos)

@@ -210,6 +210,8 @@ class TestFlashWrapperFixes:
         # SAME probe, not private copies
         assert ops._interpret_default is fa._interpret_default
         assert pa._interpret_default is fa._interpret_default
+        sc = importlib.import_module("repro.kernels.sc_matmul.sc_matmul")
+        assert sc._interpret_default is fa._interpret_default
 
     def test_raw_kernel_default_interpret_resolves(self):
         """flash_attention_kernel's default must resolve via the probe

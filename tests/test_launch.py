@@ -148,3 +148,26 @@ def test_input_specs_cover_all_kinds():
             if cell.kind == "decode":
                 tok = ins["tokens"]
                 assert tok.shape[1] == 1
+
+
+def test_compile_cache_dir_rule(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and the code sets nothing;
+    otherwise the cache is the fixed in-checkout `.jax_cache`."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.launch.compile_cache import (CHECKOUT_CACHE_DIR,
+                                            use_compile_cache)
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert use_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        assert use_compile_cache() == str(CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(
+            CHECKOUT_CACHE_DIR)
+        assert CHECKOUT_CACHE_DIR.name == ".jax_cache"
+        assert (CHECKOUT_CACHE_DIR.parent / "src" / "repro").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        cc.reset_cache()

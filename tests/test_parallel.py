@@ -16,8 +16,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 
 from repro import configs  # noqa: E402
 from repro.models import model  # noqa: E402
@@ -39,7 +39,8 @@ pytestmark = pytest.mark.skipif(
 
 
 def _mesh(shape, names):
-    return jax.make_mesh(shape, names)
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def _ref_attention(q, k, v, causal=True):
@@ -65,7 +66,7 @@ class TestRingAttention:
 
         ref = _ref_attention(q, k, v)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q, k, v: ring_attention(q, k, v, axis_name="sp"),
             mesh=mesh,
             in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),
@@ -83,7 +84,7 @@ class TestRingAttention:
         k = jax.random.normal(kk, (b, s, h, d), jnp.float32)
         v = jax.random.normal(kv, (b, s, h, d), jnp.float32)
         ref = _ref_attention(q, k, v)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q, k, v: layer_dataflow_attention(q, k, v,
                                                      axis_name="sp"),
             mesh=mesh,
@@ -99,7 +100,7 @@ class TestRingAttention:
         q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (b, s, h, d))
                    for i in range(3))
         ref = _ref_attention(q, k, v, causal=False)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q, k, v: ring_attention(q, k, v, axis_name="sp",
                                            causal=False),
             mesh=mesh,
@@ -135,7 +136,7 @@ class TestSplitKV:
                                       q_positions=q_pos,
                                       kv_positions_local=kvp)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             f, mesh=mesh,
             in_specs=(P(), P(None, "kvs"), P(None, "kvs"), P(None, "kvs")),
             out_specs=P())
@@ -162,7 +163,7 @@ class TestSplitKV:
         ref = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s_, -1),
                          v[:, :valid])
 
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q, kl, vl, kp: split_kv_attention(
                 q, kl, vl, axis_name="kvs", q_positions=q_pos,
                 kv_positions_local=kp),
@@ -185,7 +186,7 @@ class TestCompression:
             out, _ = compressed_psum({"w": g}, state, "dp")
             return out["w"]
 
-        fn = shard_map(f, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"))
+        fn = jax.shard_map(f, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"))
         out = jax.jit(fn)(g.reshape(8, 1, 64).reshape(8, 64))
         exact = jnp.mean(g, axis=0)
         tol = {"none": 1e-6, "bf16": 1e-2, "int8": 3e-2}[mode]
@@ -211,7 +212,7 @@ class TestCompression:
 
         from repro.parallel.compress import CompressionState as \
             CompressionStateLike
-        fn = jax.jit(shard_map(f, mesh=mesh,
+        fn = jax.jit(jax.shard_map(f, mesh=mesh,
                                in_specs=(P("dp"), P("dp")),
                                out_specs=(P("dp"), P("dp"))))
         exact = jnp.mean(g, axis=0)

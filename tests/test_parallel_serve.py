@@ -16,8 +16,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 
 from repro.parallel import ring_attention, split_kv_attention  # noqa: E402
 from repro.parallel.ring_attention import (  # noqa: E402
@@ -33,7 +33,7 @@ EMPTY = jnp.iinfo(jnp.int32).max
 
 
 def _mesh():
-    return jax.make_mesh((8,), ("sp",))
+    return jax.make_mesh((8,), ("sp",), axis_types=(AxisType.Auto,))
 
 
 def _rand(key, shape):
@@ -67,7 +67,7 @@ class TestGQA:
         k = _rand(1, (b, s, kvh, d))
         v = _rand(2, (b, s, kvh, d))
         ref = _ref(q, k, v)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q, k, v: ring_attention(q, k, v, axis_name="sp"),
             mesh=_mesh(),
             in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),
@@ -85,7 +85,7 @@ class TestGQA:
         q_pos = jnp.full((b, 1), s_cache - 1, jnp.int32)
         kv_pos = jnp.broadcast_to(
             jnp.arange(s_cache, dtype=jnp.int32)[None], (b, s_cache))
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q, kl, vl, kp: split_kv_attention(
                 q, kl, vl, axis_name="sp", q_positions=q_pos,
                 kv_positions_local=kp),
@@ -102,7 +102,7 @@ class TestGQA:
         k = _rand(7, (b, s, kvh, d))
         v = _rand(8, (b, s, kvh, d))
         ref = _ref(q, k, v)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q, k, v: layer_dataflow_attention(q, k, v,
                                                      axis_name="sp"),
             mesh=_mesh(),
@@ -127,7 +127,7 @@ class TestSentinelMasking:
                            EMPTY).astype(jnp.int32)[None]
         kv_pos = jnp.broadcast_to(kv_pos, (b, s))
         ref = _ref(q, k[:, :valid], v[:, :valid], causal=False)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q, k, v, kp: ring_attention(
                 q, k, v, axis_name="sp", causal=False,
                 kv_positions=kp),
@@ -154,7 +154,7 @@ class TestSentinelMasking:
         # attend all 17 valid slots
         q_pos = jnp.zeros((b, 1), jnp.int32)
         ref = _ref(q, k[:, :valid], v[:, :valid], causal=False)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q, kl, vl, kp: split_kv_attention(
                 q, kl, vl, axis_name="sp", q_positions=q_pos,
                 kv_positions_local=kp, causal=False),
@@ -187,7 +187,7 @@ class TestRingPositionsTravel:
         kv_pos = jnp.broadcast_to(jnp.asarray(perm)[None], (b, s))
         q_pos = jnp.broadcast_to(
             jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q, k, v, qp, kp: ring_attention(
                 q, k, v, axis_name="sp", causal=True,
                 q_positions=qp, kv_positions=kp),
@@ -216,7 +216,7 @@ class TestRingPositionsTravel:
         mask = q_pos[0][:, None] >= jnp.arange(sk)[None, :]
         s_ = jnp.where(mask[None, None], s_, -1e30)
         ref = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s_, -1), v)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q, k, v, qp, kp: ring_attention(
                 q, k, v, axis_name="sp", q_positions=qp,
                 kv_positions=kp),

@@ -26,7 +26,8 @@ from repro.serve import (
     make_backend,
     make_serve_mesh,
 )
-from repro.serve.mesh import kv_pool_sharding, param_shardings, replicated
+from repro.serve.mesh import (describe_devices, init_params,
+                               kv_pool_sharding, param_shardings, replicated)
 
 needs8 = pytest.mark.skipif(jax.device_count() < 8,
                             reason="needs 8 (simulated) devices")
@@ -103,6 +104,41 @@ def test_kv_pool_partitioned_on_heads_when_divisible():
     eng8 = _engine(8)
     spec8 = eng8.backend.cache.kv["k"].sharding.spec
     assert all(ax is None for ax in spec8)
+
+
+@needs8
+def test_init_params_creates_params_with_their_shardings():
+    """The engine's own init creates each parameter with its TP
+    sharding (one jitted init with out_shardings, no whole copy on one
+    device first), with the same values as the unsharded init."""
+    cfg = _cfg()
+    key = jax.random.PRNGKey(3)
+    mesh = make_serve_mesh(2)
+    params = init_params(mesh, cfg, key)
+    want = param_shardings(mesh, cfg, params)
+    for leaf, sh in zip(jax.tree_util.tree_leaves(params),
+                        jax.tree_util.tree_leaves(want)):
+        assert leaf.sharding.is_equivalent_to(sh, leaf.ndim)
+    single = init_params(make_serve_mesh(1), cfg, key)
+    for a, b in zip(jax.tree_util.tree_leaves(params),
+                    jax.tree_util.tree_leaves(single)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@needs8
+def test_kv_pool_created_with_its_sharding():
+    eng = _engine(2)
+    want = kv_pool_sharding(eng.mesh, eng.cfg)
+    for arr in eng.backend.cache.kv.values():
+        assert arr.sharding.is_equivalent_to(want, arr.ndim)
+        assert not np.asarray(arr).any()
+
+
+def test_describe_devices_reports_what_jax_sees():
+    assert describe_devices() == {
+        "platform": jax.devices()[0].platform,
+        "kind": jax.devices()[0].device_kind,
+        "count": jax.device_count()}
 
 
 @needs8
