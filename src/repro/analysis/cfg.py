@@ -71,17 +71,22 @@ def shallow_walk(node: ast.AST):
     """`ast.walk` that never crosses into a nested scope (function,
     lambda, class) — the expression-level view of one atom. The
     boundary node itself is yielded (so a nested `def` atom is
-    visible), its body is not."""
+    visible), its body is not. A `for` or `with` atom is its header
+    (target and iterable, context items): the statements of its body
+    are atoms of their own."""
     stack = [node]
     while stack:
         n = stack.pop()
         yield n
-        if isinstance(n, SCOPE_BOUNDARY) and n is not node:
-            continue
         if isinstance(n, SCOPE_BOUNDARY):
             # even as the root, a scope's body belongs to the inner CFG
             continue
-        stack.extend(ast.iter_child_nodes(n))
+        if isinstance(n, (ast.For, ast.AsyncFor)):
+            stack.extend((n.target, n.iter))
+        elif isinstance(n, (ast.With, ast.AsyncWith)):
+            stack.extend(n.items)
+        else:
+            stack.extend(ast.iter_child_nodes(n))
 
 
 def atom_bindings(atom: ast.AST) -> list[tuple[list[ast.AST], ast.AST | None]]:

@@ -102,7 +102,7 @@ must never inspect; it is created by `admit()` and destroyed by
 
 `make_backend` hands every backend the engine's `repro.serve.obs`
 Tracer (`obs`) and virtual-clock read (`clock() -> float`). A backend
-participates in observability through exactly two channels:
+participates in observability through exactly three channels:
 
   events — memory-lifecycle transitions the backend alone can see are
       emitted as TYPED obs events stamped with `clock()`, never as raw
@@ -120,7 +120,15 @@ participates in observability through exactly two channels:
       backends; every other registry namespace must be
       backend-independent — the conformance suite pins this).
       `snapshot_metrics()` reads the registry back so its dict stays
-      derivable from the registry alone.
+      derivable from the registry alone. Every `prefill_step` counts
+      `backend/prefill_positions` (max_batch x prefill_chunk) and
+      `backend/prefill_tokens` (the chunks' valid tokens).
+  spans — each forward opens `obs.span("serve.pack", phase=...)`
+      around its host arrays and their transfers and
+      `obs.span("serve.dispatch", phase=...)` around the jitted call,
+      with phase "decode" or "prefill"; they are host spans on the
+      profiler's clock while `obs.profiling` is set, and no-ops
+      otherwise.
 
 A new backend that has nothing to share or fork simply emits nothing —
 span assembly and the trace exporter treat backend events as optional
@@ -340,6 +348,13 @@ class SequenceBackend(abc.ABC):
 # ---------------------------------------------------------------------------
 
 
+def _count_prefill(reg, positions: int, chunks) -> None:
+    """A chunked-prefill forward computes `positions` (max_batch x
+    prefill_chunk) positions, of which the chunks' tokens are valid."""
+    reg.inc("backend/prefill_positions", positions)
+    reg.inc("backend/prefill_tokens", sum(n for _, n in chunks))
+
+
 @functools.lru_cache(maxsize=None)
 def _paged_steps(cfg: ModelConfig, policy: ArithmeticPolicy,
                  attn_impl: str = "gather"):
@@ -523,7 +538,6 @@ class PagedKVBackend(SequenceBackend):
         req.mem = PagedSeqState()
         ep = req.effective_prompt()
         reg = self._obs.registry
-        reg.inc("backend/n_admissions")
         reg.inc("backend/prompt_tokens", len(ep))
         if not self.ecfg.prefix_sharing:
             return AdmitPlan()
@@ -682,55 +696,65 @@ class PagedKVBackend(SequenceBackend):
             self.prefix.register(ep[:(j + 1) * page], req.mem.pages[j])
 
     def prefill_step(self, chunks: list[tuple[Request, int]]):
+        obs = self._obs
         b, c = self.ecfg.max_batch, self.ecfg.prefill_chunk
         pmax = self.ecfg.max_pages_per_seq
-        tokens = np.zeros((b, c), np.int32)
-        tables = np.full((b, pmax), TRASH_PAGE, np.int32)
-        start = np.zeros((b,), np.int32)
-        lens = np.zeros((b,), np.int32)
-        active = np.zeros((b,), bool)
-        wfrom = np.zeros((b,), np.int32)
-        for i, (req, n) in enumerate(chunks):
-            ep = req.effective_prompt()
-            tokens[i, :n] = ep[req.prefill_pos:req.prefill_pos + n]
-            tables[i, :len(req.mem.pages)] = req.mem.pages
-            start[i] = req.prefill_pos
-            lens[i] = n
-            active[i] = True
-            # positions below shared_len are resident in (possibly
-            # shared) pages: rerun the query, skip the write
-            wfrom[i] = req.mem.shared_len
-        logits, kv = self._prefill_fn(
-            self.params, jnp.asarray(tokens), self.cache.kv,
-            jnp.asarray(tables), jnp.asarray(start),
-            jnp.asarray(lens), jnp.asarray(active),
-            jnp.asarray(wfrom))
-        self.cache.kv = kv
-        for req, n in chunks:
-            old_seq = req.seq_len
-            req.prefill_pos += n
-            # a sharer rerunning inside its shared prefix already has
-            # seq_len past the cursor — coverage never shrinks
-            req.seq_len = max(req.seq_len, req.prefill_pos)
-            self._register_full_pages(req, old_seq)
+        with obs.span("serve.pack", phase="prefill"):
+            tokens = np.zeros((b, c), np.int32)
+            tables = np.full((b, pmax), TRASH_PAGE, np.int32)
+            start = np.zeros((b,), np.int32)
+            lens = np.zeros((b,), np.int32)
+            active = np.zeros((b,), bool)
+            wfrom = np.zeros((b,), np.int32)
+            for i, (req, n) in enumerate(chunks):
+                ep = req.effective_prompt()
+                tokens[i, :n] = ep[req.prefill_pos:req.prefill_pos + n]
+                tables[i, :len(req.mem.pages)] = req.mem.pages
+                start[i] = req.prefill_pos
+                lens[i] = n
+                active[i] = True
+                # positions below shared_len are resident in (possibly
+                # shared) pages: rerun the query, skip the write
+                wfrom[i] = req.mem.shared_len
+            tokens, tables, start, lens, active, wfrom = (
+                jnp.asarray(a)
+                for a in (tokens, tables, start, lens, active, wfrom))
+        with obs.span("serve.dispatch", phase="prefill"):
+            logits, kv = self._prefill_fn(
+                self.params, tokens, self.cache.kv, tables, start, lens,
+                active, wfrom)
+            self.cache.kv = kv
+        _count_prefill(obs.registry, b * c, chunks)
+        with obs.span("serve.apply", phase="prefill"):
+            for req, n in chunks:
+                old_seq = req.seq_len
+                req.prefill_pos += n
+                # a sharer rerunning inside its shared prefix already
+                # has seq_len past the cursor — coverage never shrinks
+                req.seq_len = max(req.seq_len, req.prefill_pos)
+                self._register_full_pages(req, old_seq)
         return logits
 
     def decode_step(self, reqs: list[Request]):
+        obs = self._obs
         b, pmax = self.ecfg.max_batch, self.ecfg.max_pages_per_seq
-        tokens = np.zeros((b, 1), np.int32)
-        tables = np.full((b, pmax), TRASH_PAGE, np.int32)
-        seq_lens = np.zeros((b,), np.int32)
-        active = np.zeros((b,), bool)
-        for req in reqs:
-            tokens[req.lane, 0] = req.generated[-1]
-            tables[req.lane, :len(req.mem.pages)] = req.mem.pages
-            seq_lens[req.lane] = req.seq_len
-            active[req.lane] = True
-        logits, kv = self._decode_fn(
-            self.params, jnp.asarray(tokens), self.cache.kv,
-            jnp.asarray(tables), jnp.asarray(seq_lens),
-            jnp.asarray(active))
-        self.cache.kv = kv
+        with obs.span("serve.pack", phase="decode"):
+            tokens = np.zeros((b, 1), np.int32)
+            tables = np.full((b, pmax), TRASH_PAGE, np.int32)
+            seq_lens = np.zeros((b,), np.int32)
+            active = np.zeros((b,), bool)
+            for req in reqs:
+                tokens[req.lane, 0] = req.generated[-1]
+                tables[req.lane, :len(req.mem.pages)] = req.mem.pages
+                seq_lens[req.lane] = req.seq_len
+                active[req.lane] = True
+            tokens, tables, seq_lens, active = (
+                jnp.asarray(a) for a in (tokens, tables, seq_lens, active))
+        with obs.span("serve.dispatch", phase="decode"):
+            logits, kv = self._decode_fn(
+                self.params, tokens, self.cache.kv, tables, seq_lens,
+                active)
+            self.cache.kv = kv
         return logits
 
     # -- release / accounting -----------------------------------------------
@@ -866,9 +890,8 @@ class StateSlotBackend(SequenceBackend):
         self.pool = reset_slot(self.pool, self.init_slot,
                                jnp.int32(slot))
         req.mem = SlotSeqState(slot=slot)
-        reg = self._obs.registry
-        reg.inc("backend/n_admissions")
-        reg.inc("backend/prompt_tokens", len(req.effective_prompt()))
+        self._obs.registry.inc("backend/prompt_tokens",
+                               len(req.effective_prompt()))
         return AdmitPlan()
 
     def probe_shared(self, req: Request) -> int:
@@ -891,38 +914,45 @@ class StateSlotBackend(SequenceBackend):
     # -- forwards -----------------------------------------------------------
 
     def prefill_step(self, chunks: list[tuple[Request, int]]):
+        obs = self._obs
         b, c = self.ecfg.max_batch, self.ecfg.prefill_chunk
-        tokens = np.zeros((b, c), np.int32)
-        slot_ids = np.full((b,), TRASH_SLOT, np.int32)
-        lens = np.zeros((b,), np.int32)
-        active = np.zeros((b,), bool)
-        for i, (req, n) in enumerate(chunks):
-            ep = req.effective_prompt()
-            tokens[i, :n] = ep[req.prefill_pos:req.prefill_pos + n]
-            slot_ids[i] = req.mem.slot
-            lens[i] = n
-            active[i] = True
-        logits, pool = self._prefill_fn(
-            self.params, jnp.asarray(tokens), self.pool,
-            jnp.asarray(slot_ids), jnp.asarray(lens),
-            jnp.asarray(active))
-        self.pool = pool
+        with obs.span("serve.pack", phase="prefill"):
+            tokens = np.zeros((b, c), np.int32)
+            slot_ids = np.full((b,), TRASH_SLOT, np.int32)
+            lens = np.zeros((b,), np.int32)
+            active = np.zeros((b,), bool)
+            for i, (req, n) in enumerate(chunks):
+                ep = req.effective_prompt()
+                tokens[i, :n] = ep[req.prefill_pos:req.prefill_pos + n]
+                slot_ids[i] = req.mem.slot
+                lens[i] = n
+                active[i] = True
+            tokens, slot_ids, lens, active = (
+                jnp.asarray(a) for a in (tokens, slot_ids, lens, active))
+        with obs.span("serve.dispatch", phase="prefill"):
+            logits, pool = self._prefill_fn(
+                self.params, tokens, self.pool, slot_ids, lens, active)
+            self.pool = pool
+        _count_prefill(obs.registry, b * c, chunks)
         for req, n in chunks:
             req.prefill_pos += n
             req.seq_len = req.prefill_pos
         return logits
 
     def decode_step(self, reqs: list[Request]):
+        obs = self._obs
         b = self.ecfg.max_batch
-        tokens = np.zeros((b, 1), np.int32)
-        slot_ids = np.full((b,), TRASH_SLOT, np.int32)
-        for req in reqs:
-            tokens[req.lane, 0] = req.generated[-1]
-            slot_ids[req.lane] = req.mem.slot
-        logits, pool = self._decode_fn(
-            self.params, jnp.asarray(tokens), self.pool,
-            jnp.asarray(slot_ids))
-        self.pool = pool
+        with obs.span("serve.pack", phase="decode"):
+            tokens = np.zeros((b, 1), np.int32)
+            slot_ids = np.full((b,), TRASH_SLOT, np.int32)
+            for req in reqs:
+                tokens[req.lane, 0] = req.generated[-1]
+                slot_ids[req.lane] = req.mem.slot
+            tokens, slot_ids = jnp.asarray(tokens), jnp.asarray(slot_ids)
+        with obs.span("serve.dispatch", phase="decode"):
+            logits, pool = self._decode_fn(
+                self.params, tokens, self.pool, slot_ids)
+            self.pool = pool
         return logits
 
     # -- release / accounting -----------------------------------------------

@@ -75,6 +75,16 @@ event log for span assembly and Chrome trace export
 price/energy is split across its participating lanes into each
 request's `PhaseAttribution`, so per-request joules and
 virtual-seconds by phase sum back to the run's total simulated energy.
+With `obs.profiling` set, every step also opens flat host spans on the
+JAX profiler's clock, each carrying the step's index: `serve.schedule`
+(the scheduler's decision, hwsim pricing included), `serve.fund`
+(decode write targets, admissions, chunk funding, evictions),
+the backend's `serve.pack` / `serve.dispatch` per forward,
+`serve.sample` (the sampler and its host wait), `serve.account` (the
+hwsim price, attribution, the step event and the cache utilization)
+and `serve.apply` (results, finishes, releases). Only `serve.sample` waits for the
+device, so a step whose chunks complete no prompt returns with its
+prefill forward still running.
 """
 from __future__ import annotations
 
@@ -244,10 +254,12 @@ class ServeEngine:
         """Execute one scheduler action; returns the event (a typed
         `repro.serve.obs` event, tuple-compatible with the legacy log)
         or None when there is nothing left to do."""
-        action = self.scheduler.decide(
-            self._queued_visible(), self._next_arrival(),
-            self._prefilling(), self._decoding(),
-            self.lanes.count(None), self.backend.budget())
+        self.obs.step_index += 1
+        with self.obs.span("serve.schedule"):
+            action = self.scheduler.decide(
+                self._queued_visible(), self._next_arrival(),
+                self._prefilling(), self._decoding(),
+                self.lanes.count(None), self.backend.budget())
         if action.kind == "idle":
             return None
         if action.kind == "advance":
@@ -256,11 +268,12 @@ class ServeEngine:
         ev = self._do_mixed(action)
         if ev is not None and ev.kind != "preempt_all":
             # utilization of EXECUTED batches
-            phys, logical = self.backend.utilization()
-            reg = self.obs.registry
-            reg.inc("engine/util_phys_sum", phys)
-            reg.inc("engine/util_logical_sum", logical)
-            reg.inc("engine/util_samples")
+            with self.obs.span("serve.account"):
+                phys, logical = self.backend.utilization()
+                reg = self.obs.registry
+                reg.inc("engine/util_phys_sum", phys)
+                reg.inc("engine/util_logical_sum", logical)
+                reg.inc("engine/util_samples")
         return ev
 
     def drain(self, max_steps: int = 100_000) -> None:
@@ -363,9 +376,92 @@ class ServeEngine:
         between the halves is resolved before anything runs), then the
         decode and chunked-prefill forwards, then advance the clock
         ONCE by the price of the composed token count."""
-        preempted_before = sum(r.n_preemptions
-                               for r in self.requests.values())
+        obs = self.obs
+        reg = obs.registry
+        with obs.span("serve.fund"):
+            preempted_before = sum(r.n_preemptions
+                                   for r in self.requests.values())
+            chunks, run_decode = self._fund(action)
+        # 3. decode forward over the lanes that survived funding, then
+        #    its sampler, whose host sync waits for it
+        dec_batch: list[Request] = []
+        dec_next = None
+        if run_decode:
+            dec_batch = self._decoding()
+        if dec_batch:
+            logits = self.backend.decode_step(dec_batch)
+            reg.inc("engine/decode_forwards")
+            with obs.span("serve.sample", phase="decode"):
+                dec_next = self._sample_rows(
+                    logits, [(r.lane, r) for r in dec_batch])
 
+        # 4. chunked + batched prefill forward (the backend advances
+        #    each request's prefill_pos / seq_len); nothing waits for
+        #    it unless a chunk completes its prompt (7.)
+        chunk_logits = None
+        if chunks:
+            chunk_logits = self.backend.prefill_step(chunks)
+            reg.inc("engine/prefill_forwards")
+
+        # 5. one clock advance for the whole composed step, priced and
+        #    energy-attributed once over the composed token count
+        n_total = len(dec_batch) + sum(n for _, n in chunks)
+        if n_total == 0:
+            preempted = sum(r.n_preemptions
+                            for r in self.requests.values())
+            if preempted > preempted_before:
+                # nothing ran, but the released memory makes the
+                # re-queued requests immediately prefillable —
+                # progress, not a stall (drain keeps going)
+                return obs.emit(PreemptAllEvent(ts=self.now))
+            return None
+        with obs.span("serve.account"):
+            ev = self._account(action, dec_batch, chunks, n_total)
+
+        # 6. apply decode results
+        with obs.span("serve.apply", phase="decode"):
+            for req in dec_batch:
+                req.generated.append(int(dec_next[req.lane]))
+                req.seq_len += 1
+                if req.done:
+                    self._finish(req)
+
+        # 7. apply prefill results: a chunk that completes its prompt
+        #    samples the next token from the last VALID chunk position
+        #    and flips the request to DECODE. The completing rows'
+        #    last-position logits are gathered into one (max_batch, V)
+        #    buffer so prefill first-tokens go through the SAME
+        #    compiled sampler shape as decode rounds.
+        completing = [(i, req) for i, (req, n) in enumerate(chunks)
+                      if req.prefill_pos >= len(req.effective_prompt())]
+        if completing:
+            # device-side gather of row i's last valid position (only
+            # the completing rows matter; the rest sample as ignored
+            # greedy garbage) — never pull the whole (B, C, V) chunk
+            # logits to host for a handful of rows
+            with obs.span("serve.sample", phase="prefill"):
+                b = self.ecfg.max_batch
+                pos = np.zeros((b,), np.int32)
+                for i, req in completing:
+                    pos[i] = chunks[i][1] - 1
+                last = chunk_logits[jnp.arange(b), jnp.asarray(pos)]
+                nxts = self._sample_rows(last, completing)
+            with obs.span("serve.apply", phase="prefill"):
+                for i, req in completing:
+                    req.generated.append(int(nxts[i]))
+                    if req.t_first_token is None:
+                        req.t_first_token = self.now
+                    if req.done:
+                        self._finish(req)
+                    else:
+                        req.state = RequestState.DECODE
+
+        return ev
+
+    def _fund(self, action: Action) -> tuple[list, bool]:
+        """Fund the step's memory; returns the prefill chunks
+        [(request, n_tokens)] that will run and whether a decode round
+        runs."""
         def evict_decode(**kw):
             return self._evict_newest(reason="decode_pressure", **kw)
 
@@ -411,45 +507,24 @@ class ServeEngine:
         chunks = [(r, n) for r, n in chunks
                   if r.state is RequestState.PREFILL]
 
-        # 3. decode forward over the lanes that survived funding. If
-        #    the planned chunks could not be funded at all — the
-        #    missing memory is held by OLDER requests, which eviction
-        #    never touches — fall back to a decode round so those
-        #    holders keep progressing and eventually release what the
-        #    chunk is waiting on (drain must never stall while
-        #    runnable lanes exist)
+        # If the planned chunks could not be funded at all — the
+        # missing memory is held by OLDER requests, which eviction
+        # never touches — fall back to a decode round so those holders
+        # keep progressing and eventually release what the chunk is
+        # waiting on (drain must never stall while runnable lanes
+        # exist)
         run_decode = bool(action.decode)
         if not chunks and not run_decode and self._decoding():
             self.backend.prepare_decode(self._decode_growth_order(),
                                         evict_decode)
             run_decode = True
-        dec_batch: list[Request] = []
-        dec_next = None
-        if run_decode:
-            dec_batch = self._decoding()
-        if dec_batch:
-            logits = self.backend.decode_step(dec_batch)
-            dec_next = self._sample_rows(
-                logits, [(r.lane, r) for r in dec_batch])
+        return chunks, run_decode
 
-        # 4. chunked + batched prefill forward (the backend advances
-        #    each request's prefill_pos / seq_len)
-        chunk_logits = None
-        if chunks:
-            chunk_logits = self.backend.prefill_step(chunks)
-
-        # 5. one clock advance for the whole composed step, priced and
-        #    energy-attributed once over the composed token count
-        n_total = len(dec_batch) + sum(n for _, n in chunks)
-        if n_total == 0:
-            preempted = sum(r.n_preemptions
-                            for r in self.requests.values())
-            if preempted > preempted_before:
-                # nothing ran, but the released memory makes the
-                # re-queued requests immediately prefillable —
-                # progress, not a stall (drain keeps going)
-                return self.obs.emit(PreemptAllEvent(ts=self.now))
-            return None
+    def _account(self, action: Action, dec_batch: list[Request],
+                 chunks: list, n_total: int):
+        """Advance the virtual clock by the composed step's hwsim
+        price, attribute price and energy to its lanes, and emit the
+        step event."""
         price_ns = self.cost.price(n_total)
         energy_pj = self.cost.energy(n_total)
         dur_s = price_ns * 1e-9
@@ -457,7 +532,6 @@ class ServeEngine:
         reg = self.obs.registry
         reg.inc("engine/busy_virtual_s", dur_s)
         reg.inc("engine/energy_pj", energy_pj)
-        reg.observe("engine/step_tokens", n_total)
         # split the step's price/energy across participating lanes by
         # token share — summed over all requests this reproduces the
         # run's total simulated energy exactly (modulo fp)
@@ -483,44 +557,7 @@ class ServeEngine:
             ev = PrefillStepEvent(**fields)
         else:
             ev = MixedStepEvent(**fields)
-        self.obs.emit(ev)
-
-        # 6. apply decode results
-        for req in dec_batch:
-            req.generated.append(int(dec_next[req.lane]))
-            req.seq_len += 1
-            if req.done:
-                self._finish(req)
-
-        # 7. apply prefill results: a chunk that completes its prompt
-        #    samples the next token from the last VALID chunk position
-        #    and flips the request to DECODE. The completing rows'
-        #    last-position logits are gathered into one (max_batch, V)
-        #    buffer so prefill first-tokens go through the SAME
-        #    compiled sampler shape as decode rounds.
-        completing = [(i, req) for i, (req, n) in enumerate(chunks)
-                      if req.prefill_pos >= len(req.effective_prompt())]
-        if completing:
-            # device-side gather of row i's last valid position (only
-            # the completing rows matter; the rest sample as ignored
-            # greedy garbage) — never pull the whole (B, C, V) chunk
-            # logits to host for a handful of rows
-            b = self.ecfg.max_batch
-            pos = np.zeros((b,), np.int32)
-            for i, req in completing:
-                pos[i] = chunks[i][1] - 1
-            last = chunk_logits[jnp.arange(b), jnp.asarray(pos)]
-            nxts = self._sample_rows(last, completing)
-            for i, req in completing:
-                req.generated.append(int(nxts[i]))
-                if req.t_first_token is None:
-                    req.t_first_token = self.now
-                if req.done:
-                    self._finish(req)
-                else:
-                    req.state = RequestState.DECODE
-
-        return ev
+        return self.obs.emit(ev)
 
     def _finish(self, req: Request) -> None:
         self.backend.release(req)

@@ -43,9 +43,16 @@ the virtual clock); `validate_chrome_trace` checks the required
 
 validates an exported file from the command line (CI runs this on the
 per-run trace artifact).
+
+Real time is never read here: `Tracer.span` opens a host span on the
+JAX profiler's clock (`jax.profiler.TraceAnnotation`) when
+`Tracer.profiling` is set, so the engine's phases land in the same
+trace as the device's operations, and it costs one shared no-op
+context otherwise.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import math
@@ -446,6 +453,9 @@ class DecisionEvent(Event):
         return (self.kind, self.chosen, self.ts)
 
 
+_NO_SPAN = contextlib.nullcontext()
+
+
 class Tracer:
     """One engine's observability hub: the metrics registry plus the
     level-gated structured event log.
@@ -456,6 +466,11 @@ class Tracer:
         objects.
     level="trace" — additionally retains every event in order for span
         assembly and Chrome trace export.
+
+    Independently of the level, `profiling` (default False; whoever
+    runs the profiler sets it) turns `span()` into host spans on the
+    profiler's clock, each stamped with `step_index`, the engine step
+    it belongs to.
     """
 
     LEVELS = ("metrics", "trace")
@@ -469,6 +484,17 @@ class Tracer:
         self.level = level
         self.registry = registry if registry is not None else MetricsRegistry()
         self.events: list[Event] = []
+        self.profiling = False
+        self.step_index = 0     # the engine step spans belong to
+
+    def span(self, name: str, **args):
+        """A host span named `name` for the profiler's trace, with
+        `step=step_index` and `args` as its arguments; the shared no-op
+        context unless `profiling` is set."""
+        if not self.profiling:
+            return _NO_SPAN
+        from jax.profiler import TraceAnnotation
+        return TraceAnnotation(name, step=self.step_index, **args)
 
     @property
     def tracing(self) -> bool:

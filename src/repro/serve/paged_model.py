@@ -219,37 +219,47 @@ def _paged_attn_block(lp, x, cfg: ModelConfig, policy, positions,
 def _paged_forward(params, cfg: ModelConfig, policy, tokens, kv,
                    block_tables, positions, page_idx, offset,
                    attn_core=None, paged_core=None):
-    """Full-model paged step: embed -> layers -> logits (B, S, V)."""
+    """Full-model paged step: embed -> layers -> logits (B, S, V).
+
+    Its parts run under stable named scopes (`embed`, `kv_read`,
+    `attention`, `mlp`, `kv_write`, `lm_head`), which name the device
+    operations of a profiler trace and change no computation."""
     dtype = jnp.dtype(cfg.compute_dtype)
-    x = transformer._embed_tokens(params, cfg, tokens, dtype)   # (B, S, d)
+    with jax.named_scope("embed"):
+        x = transformer._embed_tokens(params, cfg, tokens, dtype)  # (B, S, d)
 
     def ln(lnp, y):
         return L.rmsnorm(lnp, y, cfg.norm_eps)
 
     def body(carry, lp):
         x, ck, cv, li = carry
-        ckl = jax.lax.dynamic_index_in_dim(ck, li, 0, False)
-        cvl = jax.lax.dynamic_index_in_dim(cv, li, 0, False)
-        h, ckl, cvl = _paged_attn_block(
-            lp, ln(lp["ln1"], x), cfg, policy, positions,
-            ckl, cvl, block_tables, page_idx, offset,
-            attn_core=attn_core, paged_core=paged_core)
+        with jax.named_scope("kv_read"):
+            ckl = jax.lax.dynamic_index_in_dim(ck, li, 0, False)
+            cvl = jax.lax.dynamic_index_in_dim(cv, li, 0, False)
+        with jax.named_scope("attention"):
+            h, ckl, cvl = _paged_attn_block(
+                lp, ln(lp["ln1"], x), cfg, policy, positions,
+                ckl, cvl, block_tables, page_idx, offset,
+                attn_core=attn_core, paged_core=paged_core)
         x = x + h
-        if cfg.family == "moe":
-            f, _ = M.moe_ffn(lp["moe"], ln(lp["ln2"], x), cfg, policy)
-        else:
-            f = L.ffn(lp["ffn"], ln(lp["ln2"], x),
-                      cfg.act, cfg.glu, policy)
+        with jax.named_scope("mlp"):
+            if cfg.family == "moe":
+                f, _ = M.moe_ffn(lp["moe"], ln(lp["ln2"], x), cfg, policy)
+            else:
+                f = L.ffn(lp["ffn"], ln(lp["ln2"], x),
+                          cfg.act, cfg.glu, policy)
         x = x + f
-        ck = jax.lax.dynamic_update_index_in_dim(ck, ckl, li, 0)
-        cv = jax.lax.dynamic_update_index_in_dim(cv, cvl, li, 0)
+        with jax.named_scope("kv_write"):
+            ck = jax.lax.dynamic_update_index_in_dim(ck, ckl, li, 0)
+            cv = jax.lax.dynamic_update_index_in_dim(cv, cvl, li, 0)
         return (x, ck, cv, li + 1), None
 
     (x, ck, cv, _), _ = jax.lax.scan(
         body, (x, kv["k"], kv["v"], jnp.zeros((), jnp.int32)),
         params["layers"])
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = transformer._logits(params, cfg, x)                # (B, S, V)
+    with jax.named_scope("lm_head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = transformer._logits(params, cfg, x)            # (B, S, V)
     return logits, {"k": ck, "v": cv}
 
 

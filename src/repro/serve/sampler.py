@@ -95,8 +95,10 @@ def sample_tokens(logits, temperature, top_k, top_p, seed, pos):
     `(B,)` i32 tokens. The engine calls this at its fixed
     `(max_batch, vocab)` shape, so it compiles once per geometry; rows
     the caller does not use (idle lanes, non-completing chunks) cost
-    nothing but flops — every lane's draw is independent."""
-    return jax.vmap(_sample_lane)(
-        jnp.asarray(logits), jnp.asarray(temperature, jnp.float32),
-        jnp.asarray(top_k, jnp.int32), jnp.asarray(top_p, jnp.float32),
-        jnp.asarray(seed, jnp.uint32), jnp.asarray(pos, jnp.int32))
+    nothing but flops — every lane's draw is independent. Its device
+    operations run under the named scope `sampler`."""
+    with jax.named_scope("sampler"):
+        return jax.vmap(_sample_lane)(
+            jnp.asarray(logits), jnp.asarray(temperature, jnp.float32),
+            jnp.asarray(top_k, jnp.int32), jnp.asarray(top_p, jnp.float32),
+            jnp.asarray(seed, jnp.uint32), jnp.asarray(pos, jnp.int32))

@@ -54,3 +54,20 @@ class Cache:
         logits, new_kv = self._decode(params, tokens, cache.kv)
         cache = cache.replace(kv=new_kv)
         return logits, cache.kv
+
+
+class SpannedCache:
+    """The donating call inside a `with` block, rebound in the same
+    block: the `with` atom is its header only, so the call is seen
+    once, where it stands."""
+
+    def __init__(self, step, kv, span):
+        self._decode = jax.jit(step, donate_argnums=(2,))
+        self.kv = kv
+        self.span = span
+
+    def step(self, params, tokens):
+        with self.span("dispatch"):
+            logits, kv = self._decode(params, tokens, self.kv)
+            self.kv = kv
+        return logits, self.kv
