@@ -35,9 +35,9 @@ Three step builders, all jit-stable under continuous batching:
     One token for every lane of a FIXED max-batch — the chunked pass
     with C == 1 query and the position taken from seq_lens.
 
-Inactive rows / padding chunk positions scatter into the reserved
-trash page 0 and are excluded from every valid query's mask, so the
-compiled steps never see a data-dependent shape.
+Inactive rows / padding chunk positions scatter into each layer's
+reserved trash page 0 and are excluded from every valid query's mask,
+so the compiled steps never see a data-dependent shape.
 
 Only attention families (dense / moe) are supported: paged KV is
 meaningless for the recurrent-state families (rwkv6 / zamba2), which
@@ -163,57 +163,67 @@ def make_fused_paged_core(cfg: ModelConfig, policy: ArithmeticPolicy):
 
 
 def _paged_attn_block(lp, x, cfg: ModelConfig, policy, positions,
-                      ckl, cvl, block_tables, page_idx, offset,
+                      ck, cv, base, block_tables, page_idx, offset,
                       attn_core=None, paged_core=None):
     """One layer's attention with paged K/V. x: (B, S, d).
 
-    ckl/cvl: this layer's page pool (P, page, KV, Dh); positions,
-    page_idx, offset: (B, S) — the absolute position of every query
-    token and its scatter coordinates in the pool (trash page for
-    inactive / padding tokens). Returns (attn_out, new ckl, new cvl).
+    ck/cv: the whole stacked pool flattened to (L*P, page, KV, Dh), in
+    which this layer owns pages [base, base + P) and its trash page is
+    base + TRASH_PAGE. block_tables (B, Pmax), page_idx (B, S): page
+    ids within the layer, in [0, P); positions, offset: (B, S) — the
+    absolute position of every query token and its slot in the page
+    (trash page for inactive / padding tokens). Returns (attn_out,
+    new ck, new cv), the pool changed only in the slots written here.
 
+    Its parts run under the scopes `attention` (projections and core),
+    `kv_write` (the scatter) and `kv_read` (the block-table gather).
     Two occupants share the attention seam at this call site:
     `attn_core` consumes the GATHERED (B, Smax, KV, Dh) view (default
     `_attn_core`; the sharded backend's mesh cores), while
-    `paged_core(qg, ckl, cvl, block_tables, positions)` consumes the
+    `paged_core(qg, ck, cv, block_tables, positions)` consumes the
     raw pool + block tables so the fused kernel can walk pages
     in-kernel — when it is set, the gather below never happens.
     """
     b, s, _ = x.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     p = lp["attn"]
-    qh = L.mm(x, p["wq"], policy).reshape(b, s, h, hd)
-    kh = L.mm(x, p["wk"], policy).reshape(b, s, kvh, hd)
-    vh = L.mm(x, p["wv"], policy).reshape(b, s, kvh, hd)
-    if cfg.qk_norm:
-        qh = L.headwise_rmsnorm(p["q_norm"], qh, cfg.norm_eps)
-        kh = L.headwise_rmsnorm(p["k_norm"], kh, cfg.norm_eps)
-    qh = L.apply_rope(qh, positions, cfg.rope_theta)
-    kh = L.apply_rope(kh, positions, cfg.rope_theta)
+    with jax.named_scope("attention"):
+        qh = L.mm(x, p["wq"], policy).reshape(b, s, h, hd)
+        kh = L.mm(x, p["wk"], policy).reshape(b, s, kvh, hd)
+        vh = L.mm(x, p["wv"], policy).reshape(b, s, kvh, hd)
+        if cfg.qk_norm:
+            qh = L.headwise_rmsnorm(p["q_norm"], qh, cfg.norm_eps)
+            kh = L.headwise_rmsnorm(p["k_norm"], kh, cfg.norm_eps)
+        qh = L.apply_rope(qh, positions, cfg.rope_theta)
+        kh = L.apply_rope(kh, positions, cfg.rope_theta)
 
-    # scatter the new tokens' K/V into their (page, slot) coordinates
-    ckl = ckl.at[page_idx, offset].set(kh.astype(ckl.dtype))
-    cvl = cvl.at[page_idx, offset].set(vh.astype(cvl.dtype))
+    # scatter the new tokens' K/V into their (page, slot) coordinates,
+    # in place in the carried pool
+    with jax.named_scope("kv_write"):
+        page_idx = page_idx + base
+        ck = ck.at[page_idx, offset].set(kh.astype(ck.dtype))
+        cv = cv.at[page_idx, offset].set(vh.astype(cv.dtype))
 
-    g = h // kvh
-    qg = qh.reshape(b, s, kvh, g, hd)
-    if paged_core is not None:
-        # fused path: the kernel reads the pool just written above, so
-        # chunk tokens still attend to earlier tokens of the same chunk
-        ctx = paged_core(qg, ckl, cvl, block_tables, positions)
-    else:
-        # gather each row's block table back to a contiguous KV view:
-        # (B, Pmax, page, KV, Dh) -> (B, Smax, KV, Dh), position order —
-        # this view already contains the K/V scattered just above, so
-        # chunk tokens attend to earlier tokens of the same chunk
-        pmax, page = block_tables.shape[1], ckl.shape[1]
-        smax = pmax * page
-        kall = ckl[block_tables].reshape(b, smax, kvh, hd).astype(x.dtype)
-        vall = cvl[block_tables].reshape(b, smax, kvh, hd).astype(x.dtype)
-        core = attn_core if attn_core is not None else _attn_core
-        ctx = core(qg, kall, vall, positions, cfg, policy)
-    ctx = ctx.reshape(b, s, h * hd)
-    return L.mm(ctx, p["wo"], policy), ckl, cvl
+    with jax.named_scope("kv_read"):
+        block_tables = block_tables + base
+        if paged_core is None:
+            # gather each row's block table back to a contiguous KV
+            # view: (B, Pmax, page, KV, Dh) -> (B, Smax, KV, Dh),
+            # position order — it already holds the K/V scattered
+            # just above, so chunk tokens attend to earlier tokens of
+            # the same chunk (the fused kernel reads the same pool)
+            smax = block_tables.shape[1] * ck.shape[1]
+            kall = ck[block_tables].reshape(b, smax, kvh, hd).astype(x.dtype)
+            vall = cv[block_tables].reshape(b, smax, kvh, hd).astype(x.dtype)
+
+    with jax.named_scope("attention"):
+        qg = qh.reshape(b, s, kvh, h // kvh, hd)
+        if paged_core is not None:
+            ctx = paged_core(qg, ck, cv, block_tables, positions)
+        else:
+            core = attn_core if attn_core is not None else _attn_core
+            ctx = core(qg, kall, vall, positions, cfg, policy)
+        return L.mm(ctx.reshape(b, s, h * hd), p["wo"], policy), ck, cv
 
 
 def _paged_forward(params, cfg: ModelConfig, policy, tokens, kv,
@@ -221,26 +231,30 @@ def _paged_forward(params, cfg: ModelConfig, policy, tokens, kv,
                    attn_core=None, paged_core=None):
     """Full-model paged step: embed -> layers -> logits (B, S, V).
 
+    kv holds the stacked pool (L, P, page, KV, Dh). The scan carries it
+    flattened to (L*P, page, KV, Dh), a bitcast, and layer li scatters
+    into and gathers from its own pages, at ids offset by li*P, in
+    place: no layer's pool is sliced out or written back.
+
     Its parts run under stable named scopes (`embed`, `kv_read`,
     `attention`, `mlp`, `kv_write`, `lm_head`), which name the device
     operations of a profiler trace and change no computation."""
     dtype = jnp.dtype(cfg.compute_dtype)
     with jax.named_scope("embed"):
         x = transformer._embed_tokens(params, cfg, tokens, dtype)  # (B, S, d)
+    n_layers, n_pages = kv["k"].shape[:2]
+    pool_shape = kv["k"].shape
 
     def ln(lnp, y):
         return L.rmsnorm(lnp, y, cfg.norm_eps)
 
     def body(carry, lp):
-        x, ck, cv, li = carry
-        with jax.named_scope("kv_read"):
-            ckl = jax.lax.dynamic_index_in_dim(ck, li, 0, False)
-            cvl = jax.lax.dynamic_index_in_dim(cv, li, 0, False)
+        x, ck, cv, base = carry
         with jax.named_scope("attention"):
-            h, ckl, cvl = _paged_attn_block(
-                lp, ln(lp["ln1"], x), cfg, policy, positions,
-                ckl, cvl, block_tables, page_idx, offset,
-                attn_core=attn_core, paged_core=paged_core)
+            xn = ln(lp["ln1"], x)
+        h, ck, cv = _paged_attn_block(
+            lp, xn, cfg, policy, positions, ck, cv, base, block_tables,
+            page_idx, offset, attn_core=attn_core, paged_core=paged_core)
         x = x + h
         with jax.named_scope("mlp"):
             if cfg.family == "moe":
@@ -249,18 +263,17 @@ def _paged_forward(params, cfg: ModelConfig, policy, tokens, kv,
                 f = L.ffn(lp["ffn"], ln(lp["ln2"], x),
                           cfg.act, cfg.glu, policy)
         x = x + f
-        with jax.named_scope("kv_write"):
-            ck = jax.lax.dynamic_update_index_in_dim(ck, ckl, li, 0)
-            cv = jax.lax.dynamic_update_index_in_dim(cv, cvl, li, 0)
-        return (x, ck, cv, li + 1), None
+        return (x, ck, cv, base + n_pages), None
 
+    flat = (n_layers * n_pages,) + pool_shape[2:]
     (x, ck, cv, _), _ = jax.lax.scan(
-        body, (x, kv["k"], kv["v"], jnp.zeros((), jnp.int32)),
+        body, (x, kv["k"].reshape(flat), kv["v"].reshape(flat),
+               jnp.zeros((), jnp.int32)),
         params["layers"])
     with jax.named_scope("lm_head"):
         x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
         logits = transformer._logits(params, cfg, x)            # (B, S, V)
-    return logits, {"k": ck, "v": cv}
+    return logits, {"k": ck.reshape(pool_shape), "v": cv.reshape(pool_shape)}
 
 
 # ---------------------------------------------------------------------------

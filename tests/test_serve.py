@@ -424,6 +424,68 @@ def test_chunked_prefill_logits_match_dense(dense_setup):
         np.asarray(kv["k"][:, pages]), np.asarray(kv_before[:, pages]))
 
 
+@pytest.mark.parametrize("step", ["chunked_prefill", "decode"])
+def test_paged_step_writes_only_the_slots_its_tokens_own(dense_setup, step):
+    """One step on a pool of noise matches the whole-prompt reference's
+    logits and K/V, and changes the pool only at the (layer, page, slot)
+    entries its tokens own and in each layer's trash page: a layer that
+    reads or writes at the wrong page offset lands in another layer's
+    pages, or leaves its own unwritten."""
+    cfg, params = dense_setup
+    page, n_pages, b, pmax, chunk_c = 4, 12, 2, 4, 16
+    pages = np.array([5, 2, 9], np.int32)              # out of order
+    prompt = np.arange(3, 14, dtype=np.int32)          # 11 tokens
+    shape = (cfg.n_layers, n_pages, page, cfg.n_kv_heads,
+             cfg.resolved_head_dim)
+    keys = jax.random.split(jax.random.PRNGKey(7))
+    noise = {n: jax.random.normal(k, shape) for n, k in zip("kv", keys)}
+    ref = jax.jit(make_paged_prefill(cfg))
+
+    def padded(toks):
+        out = np.zeros((1, len(pages) * page), np.int32)
+        out[0, :len(toks)] = toks
+        return jnp.asarray(out)
+
+    ref_logits, ref_kv = ref(params, padded(prompt), noise, pages)
+    tables = np.full((b, pmax), TRASH_PAGE, np.int32)
+    tables[0, :len(pages)] = pages
+    if step == "chunked_prefill":
+        before, first = noise, 0
+        tokens = np.zeros((b, chunk_c), np.int32)
+        tokens[0, :len(prompt)] = prompt
+        logits, kv = make_paged_chunked_prefill(cfg)(
+            params, jnp.asarray(tokens), before, jnp.asarray(tables),
+            jnp.zeros((b,), jnp.int32),
+            jnp.asarray([len(prompt), 0], jnp.int32),
+            jnp.asarray([True, False]), jnp.zeros((b,), jnp.int32))
+        got = logits[0, :len(prompt)]
+    else:
+        # the prompt but its last token is resident; decode that token
+        first = len(prompt) - 1
+        _, before = ref(params, padded(prompt[:first]), noise, pages)
+        logits, kv = make_paged_decode(cfg)(
+            params, jnp.asarray([[prompt[-1]], [0]], jnp.int32), before,
+            jnp.asarray(tables), jnp.asarray([first, 0], jnp.int32),
+            jnp.asarray([True, False]))
+        got = logits[:1]
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(ref_logits[first:len(prompt)]),
+                               rtol=1e-4, atol=1e-4)
+
+    own = np.zeros(shape[:3], bool)                    # (layer, page, slot)
+    own[:, TRASH_PAGE] = True
+    for pos in range(first, len(prompt)):
+        pg, sl = pages[pos // page], pos % page
+        own[:, pg, sl] = True
+        for name in "kv":
+            np.testing.assert_allclose(
+                np.asarray(kv[name][:, pg, sl]),
+                np.asarray(ref_kv[name][:, pg, sl]), rtol=1e-4, atol=1e-4)
+    for name in "kv":
+        new, old = np.asarray(kv[name]), np.asarray(before[name])
+        np.testing.assert_array_equal(new[~own], old[~own])
+
+
 def test_paged_model_rejects_recurrent_families():
     cfg = configs.get_config("rwkv6_3b", smoke=True)
     with pytest.raises(ValueError, match="dense/moe"):
